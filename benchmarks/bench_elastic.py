@@ -15,7 +15,9 @@ mesh — for each of the three degradation paths the 3D refactor added
 
 Two state sizes show the scaling.  Needs 8 host devices, so the
 measurement runs in a child process with XLA_FLAGS set (the parent —
-``benchmarks/run.py`` — keeps the default single device).  Emits
+``benchmarks/run.py`` — keeps the default single device).  The child is
+pinned to the CPU backend: the parent may already hold the chip, and these
+are host-device timings, not chip numbers.  Emits
 machine-readable ``BENCH_elastic.json`` (override: BENCH_ELASTIC_JSON).
 """
 from __future__ import annotations
@@ -95,7 +97,7 @@ def _worker() -> None:
         base = results[f"{label}.baseline_2d_ms"]
         dp = results[f"{label}.dp_only_ms"]
         results[f"{label}.dp_vs_baseline"] = round(dp / base, 3)
-        print(f"{label:5s} state {size_mb:6.2f} MB: "
+        print(f"{label:5s} state {size_mb:6.2f} MB, 8 CPU host devices: "
               f"2d={base:.1f}ms dp={dp:.1f}ms "
               f"tp={results[f'{label}.tp_repartition_ms']:.1f}ms "
               f"ep={results[f'{label}.expert_drop_ms']:.1f}ms "
@@ -107,6 +109,7 @@ def _worker() -> None:
 def main() -> List[str]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(ROOT, "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
@@ -119,7 +122,7 @@ def main() -> List[str]:
     path = os.environ.get("BENCH_ELASTIC_JSON", "BENCH_elastic.json")
     with open(path) as f:
         results = json.load(f)
-    rows = [f"elastic_reshard_{k.replace('.', '_')},{v * 1e3:.1f},"
+    rows = [f"elastic_reshard_{k.replace('.', '_')},{v * 1e3:.1f},cpu"
             for k, v in sorted(results.items()) if k.endswith("_ms")]
     # acceptance: the dp-only path must not regress vs the 2D baseline
     # (x2 tolerance absorbs timer noise on ~ms restores)

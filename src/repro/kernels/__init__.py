@@ -21,12 +21,9 @@ Kernels (DESIGN.md S3):
                     SDC scrubber's leaf checksums (one reduction idiom,
                     two consumers — docs/checkpointing.md, docs/sdc.md).
 
-All validated against their oracles in interpret mode on CPU (this container
-has no TPU); on TPU hardware the same pallas_call lowers natively.
+Checked two ways without a chip: every kernel against its oracle in
+interpret mode on CPU (tests/test_kernels.py), and every main-path kernel
+compiled for a described v5e chip at published widths, asserting the
+lowering is a Mosaic ``tpu_custom_call`` (tests/test_chip_compile.py).
+``chip_smoke.py`` runs block_hash and abft_matmul compiled on a real chip.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases; kernels
-# use this alias so both spellings work.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")

@@ -34,8 +34,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 ROWS = 8        # block-hash rows per grid step (sublane tile)
 WTILE = 2048    # words reduced per grid step along the word axis
@@ -76,7 +76,7 @@ def hash_rows(w, *, interpret=False):
         in_specs=[pl.BlockSpec((ROWS, WTILE), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((ROWS, LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nbp, LANES), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(w)

@@ -96,8 +96,19 @@ def batched_block_hashes(leaves, block_elems: int = BLOCK_ELEMS, *,
         return []
     if use_kernel is None:
         use_kernel = _default_use_kernel()
-    return _batched_block_hashes(list(leaves), int(block_elems),
-                                 bool(use_kernel), bool(interpret))
+    # one dispatch per device set: a jit takes its arguments from one set,
+    # and the per-device shards of a sharded state each live on their own
+    groups = {}
+    for i, x in enumerate(leaves):
+        groups.setdefault(frozenset(x.devices()), []).append(i)
+    out = [None] * len(leaves)
+    for idx in groups.values():
+        hashes = _batched_block_hashes([leaves[i] for i in idx],
+                                       int(block_elems), bool(use_kernel),
+                                       bool(interpret))
+        for i, h in zip(idx, hashes):
+            out[i] = h
+    return out
 
 
 def checksum_words(x, block_elems: int = BLOCK_ELEMS):

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 BLOCK = 256
 ROWS = 64
@@ -71,7 +70,7 @@ def quantize_blocks(x, *, interpret=False):
             jax.ShapeDtypeStruct((nbp, BLOCK), jnp.int8),
             jax.ShapeDtypeStruct((nbp, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)
@@ -96,7 +95,7 @@ def dequantize_blocks(q, scales, *, interpret=False):
         ],
         out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nbp, BLOCK), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, scales)

@@ -19,7 +19,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -106,7 +105,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0, softcap=0.0,
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

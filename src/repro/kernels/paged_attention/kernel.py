@@ -27,7 +27,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -117,7 +116,7 @@ def paged_attention_rkgd(q, k_pages, v_pages, page_tables, lengths, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, K, G, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_tables, lengths, q, k_pages, v_pages)

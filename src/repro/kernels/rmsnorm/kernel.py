@@ -11,7 +11,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 ROWS = 128
 
@@ -37,7 +36,7 @@ def rms_norm_2d(x, w, *, eps=1e-6, interpret=False):
         ],
         out_specs=pl.BlockSpec((rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)

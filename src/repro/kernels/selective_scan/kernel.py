@@ -20,7 +20,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hout_ref,
@@ -82,7 +81,7 @@ def selective_scan_kernel(x, dt, bm, cm, a, h0, *, block_c=512, chunk=128,
             jax.ShapeDtypeStruct((B, Di, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bc, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, bm, cm, a, h0)

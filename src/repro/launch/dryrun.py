@@ -114,8 +114,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                                              multi_pod=multi_pod)
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-            cost = cost[0] if cost else {}
         rec = {
             **meta,
             "status": "ok",

@@ -9,14 +9,8 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axis_names):
-    """jax.make_mesh across jax versions: axis_types (and AxisType itself)
-    only exist in newer releases; older ones default to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+def _auto(axis_names):
+    return (jax.sharding.AxisType.Auto,) * len(axis_names)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, expert: int = 0,
@@ -37,10 +31,12 @@ def make_host_mesh(data: int = 1, model: int = 1, expert: int = 0,
     n = len(jax.devices())
     if expert:
         assert data * model * expert <= n, (data, model, expert, n)
-        return make_mesh_compat(
-            (data, model, expert), axis_names or ("data", "model", "expert"))
+        axes = axis_names or ("data", "model", "expert")
+        return jax.make_mesh((data, model, expert), axes,
+                             axis_types=_auto(axes))
     assert data * model <= n, (data, model, n)
-    return make_mesh_compat((data, model), axis_names or ("data", "model"))
+    axes = axis_names or ("data", "model")
+    return jax.make_mesh((data, model), axes, axis_types=_auto(axes))
 
 
 def host_device_map(num_hosts: int, devices=None):

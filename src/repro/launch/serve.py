@@ -24,16 +24,15 @@ import time
 
 import jax
 
-from repro.configs import ALL_ARCHS
 from repro.core import CheckpointManager, FaultInjector
-from repro.models import get_config, init_params
+from repro.launch.common import add_model_args, model_config, use_compile_cache
+from repro.models import init_params
 from repro.serve import ServeEngine, make_standby_source, pctl
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="granite-3-8b", choices=ALL_ARCHS)
-    ap.add_argument("--tiny", action="store_true")
+    add_model_args(ap)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
@@ -89,7 +88,8 @@ def main(argv=None) -> int:
     ap.add_argument("--risk-threshold", type=float, default=0.8)
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, tiny=args.tiny)
+    use_compile_cache()
+    cfg = model_config(args)
     if not cfg.has_decode:
         print(f"{args.arch} is encoder-only; no decode loop")
         return 1

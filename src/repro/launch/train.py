@@ -1,7 +1,7 @@
 """Fault-tolerant training driver (the end-to-end launcher).
 
     PYTHONPATH=src python -m repro.launch.train --arch granite-3-8b --tiny \
-        --steps 200 --ckpt-dir /tmp/ckpt --policy young_daly --async-save
+        --steps 200 --ckpt-dir out/ckpt --policy young_daly --async-save
 
 Wires the full DeLIA stack around the BSP training loop: checkpoint policy
 (Young/Daly or fixed), sync/async sharded checkpoints (+ optional int8
@@ -17,53 +17,37 @@ to watch detection + rollback happen.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import sys
 import time
 
 import jax
 
-from repro.configs import ALL_ARCHS
 from repro.core import (Dependability, DependabilityConfig, FaultInjector,
                         SystemModel, run_with_recovery)
 from repro.data import make_pipeline
+from repro.launch.common import add_model_args, model_config, use_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.models import get_config
 from repro.sharding.api import mesh_context, resolve
 from repro.sharding.rules import state_specs
 from repro.train import init_state, make_train_step
 
 
-def build(args):
-    cfg = get_config(args.arch, tiny=args.tiny)
-    overrides = {}
-    if args.layers:
-        overrides["num_layers"] = args.layers
-    if args.d_model:
-        overrides.update(d_model=args.d_model,
-                         num_heads=max(args.d_model // 64, 1),
-                         num_kv_heads=max(args.d_model // 128, 1),
-                         head_dim=64, d_ff=args.d_model * 4)
-    if overrides:
-        overrides.setdefault("pad_heads_to", 0)
-        cfg = dataclasses.replace(cfg, **overrides)
-    return cfg
-
-
-def main(argv=None) -> int:
+def run(argv=None) -> dict:
+    """Parse ``argv``, train under the guard, and return the outcome:
+    ``status``, ``restarts``, every step record in the order run
+    (``steps``: a step rolled back and run again appears twice), and the
+    number of ``saves`` and ``delta_saves``."""
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="granite-3-8b", choices=ALL_ARCHS)
-    ap.add_argument("--tiny", action="store_true",
-                    help="reduced config (CPU-runnable)")
-    ap.add_argument("--layers", type=int, default=0)
-    ap.add_argument("--d-model", type=int, default=0)
+    add_model_args(ap)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--data-par", type=int, default=1)
     ap.add_argument("--model-par", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", required=True,
+                    help="checkpoint directory; a run resumes from the "
+                         "newest checkpoint it finds here")
     ap.add_argument("--policy", default="young_daly",
                     choices=["young_daly", "every_n", "risk_adjusted"])
     ap.add_argument("--every-n", type=int, default=10)
@@ -111,7 +95,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    cfg = build(args)
+    use_compile_cache()
+    cfg = model_config(args)
     mesh = make_host_mesh(args.data_par, args.model_par)
     tp = args.model_par
     specs = state_specs(cfg, tp)
@@ -198,7 +183,10 @@ def main(argv=None) -> int:
             injector = injector or FaultInjector()
             injector.schedule_bitflip(int(step_s), leaf, int(bit_s))
 
+        steps = []
+
         def on_metrics(step, rec):
+            steps.append(rec)
             if step % 10 == 0 or step == args.steps:
                 print(f"[train] step {step:5d} loss={rec['loss']:.4f} "
                       f"gnorm={rec['grad_norm']:.3f} "
@@ -238,7 +226,13 @@ def main(argv=None) -> int:
             print(f"[train] metrics snapshot: {args.metrics_snapshot}")
         obs.close()
     dep.stop()
-    return 0
+    return {"status": info["status"], "restarts": info["restarts"],
+            "steps": steps, "saves": n_saves, "delta_saves": n_delta}
+
+
+def main(argv=None) -> int:
+    """The CLI: non-zero unless the run finished ``done``."""
+    return 0 if run(argv)["status"] == "done" else 1
 
 
 if __name__ == "__main__":

@@ -375,15 +375,8 @@ def _embed_lookup(cfg, table, tokens):
         vals = jnp.where(ok[..., None], vals, jnp.zeros((), tab.dtype))
         return jax.lax.psum(vals, "model")
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    kws = dict(mesh=mesh, in_specs=(P(TP, None), P(b_ax, None)),
-               out_specs=P(b_ax, None, None))
-    try:
-        sm = shard_map(f, check_vma=False, **kws)
-    except TypeError:
-        sm = shard_map(f, check_rep=False, **kws)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P(TP, None), P(b_ax, None)),
+                       out_specs=P(b_ax, None, None), check_vma=False)
     return sm(table, tokens)
 
 

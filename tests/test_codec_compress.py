@@ -74,18 +74,11 @@ def test_compressed_psum_in_shard_map():
     grads = {"w": jnp.linspace(-2, 2, 256)}
     ef = ef_state_init(grads)
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
     def f(g, e):
         return compressed_psum(g, e, "data")
 
-    kws = dict(mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
-    try:
-        sm = shard_map(f, check_vma=False, **kws)
-    except TypeError:
-        sm = shard_map(f, check_rep=False, **kws)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     red, new_ef = sm(grads, ef)
     np.testing.assert_allclose(np.asarray(red["w"]),
                                np.asarray(grads["w"]), atol=2e-2)

@@ -58,8 +58,8 @@ def test_elastic_reshard_restore(tmp_path):
     ref_loss = float(m["loss"])
 
     # mesh A: (2 data, 2 model); 3 steps then checkpoint
-    from repro.launch.mesh import make_mesh_compat
-    mesh_a = make_mesh_compat((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh_a = make_host_mesh(2, 2)
     sh_a = sharded_state(mesh_a, 2)
     data2 = make_pipeline(cfg, 16, 4)
     with mesh_context(mesh_a):
@@ -108,10 +108,10 @@ def test_restore_onto_different_shard_layout(tmp_path):
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core import CheckpointManager
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_host_mesh
 
-    mesh_a = make_mesh_compat((4, 2), ("data", "model"))
-    mesh_b = make_mesh_compat((2, 1), ("data", "model"))
+    mesh_a = make_host_mesh(4, 2)
+    mesh_b = make_host_mesh(2, 1)
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 6, 128), jnp.float32)
     y = jnp.arange(512, dtype=jnp.int32)
     state = {{
@@ -171,8 +171,8 @@ def test_sharded_training_matches_single_device(tmp_path):
         ref, m = step(ref, data.next_batch())
     ref_loss = float(m["loss"])
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(2, 2)
     specs = state_specs(cfg, 2)
     sh = jax.tree.map(lambda s: resolve(s, mesh), specs,
                       is_leaf=lambda x: x.__class__.__name__ == "PartitionSpec")
@@ -193,6 +193,33 @@ def test_sharded_training_matches_single_device(tmp_path):
 
 
 @pytest.mark.slow
+def test_delta_save_of_a_sharded_state(tmp_path):
+    """Delta saves hash every device shard; shards on different devices
+    must not meet in one jitted dispatch.  Full then delta save of a (2,2)
+    sharded state restores bit-exact."""
+    _run(f"""
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import CheckpointManager
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(2, 2)
+    sh = NamedSharding(mesh, P("data", "model"))
+    x = jax.device_put(jnp.arange(512 * 512, dtype=jnp.float32)
+                       .reshape(512, 512), sh)
+    m = CheckpointManager({str(tmp_path)!r}, delta=True, delta_block=1024,
+                          fsync="none")
+    m.save(1, {{"x": x}})
+    y = x.at[0, 0].add(1.0)
+    st = m.save(2, {{"x": y}})
+    assert st.kind == "delta", st
+    got, _, step, _ = m.restore_latest(like={{"x": y}})
+    assert step == 2
+    np.testing.assert_array_equal(np.asarray(got["x"]), np.asarray(y))
+    """, devices=4)
+
+
 def test_dryrun_single_cell_compiles():
     """End-to-end proof on the real 512-device production mesh (slow)."""
     _run("""
