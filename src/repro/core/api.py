@@ -29,6 +29,7 @@ from repro.core.failures import CorruptionDetected, StragglerWatchdog
 from repro.core.heartbeat import HeartbeatEmitter, HeartbeatMonitor
 from repro.core.policy import CheckpointPolicy, SystemModel
 from repro.core.signals import TerminationSignal
+from repro.obs.metrics import span
 from repro.sdc import LossSentinel, StateScrubber
 
 
@@ -161,9 +162,15 @@ class Dependability:
         """Wire a ``repro.obs.Observability`` through this facade: saves,
         restores, SDC detections, and heartbeat failures/rejoins all emit
         onto its bus, and the measured R/D terms flow into the policy via
-        ``observe_recovery``.  Call before or after ``start()`` — the
-        monitor picks the handle up either way."""
+        ``observe_recovery``.  The handle also turns on the spans of the
+        training loop, the checkpoint manager and the scrubber
+        (``repro.obs.metrics.span``), which are no-ops without it.  Call
+        before or after ``start()`` — the monitor picks the handle up
+        either way."""
         self.obs = obs
+        self.manager.obs = obs
+        if self.scrubber is not None:
+            self.scrubber.obs = obs
         if self.monitor is not None:
             self.monitor.obs = obs
         return self
@@ -266,12 +273,13 @@ class Dependability:
         CorruptionDetected when the loss looks corrupted."""
         if self.sentinel is None:
             return
-        reason = self.sentinel.observe(
-            step, float(metrics.get("loss", 0.0)),
-            grad_norm=(float(metrics["grad_norm"])
-                       if "grad_norm" in metrics else None),
-            nonfinite=(float(metrics["nonfinite"])
-                       if "nonfinite" in metrics else None))
+        with span(self.obs, "sdc.loss"):
+            reason = self.sentinel.observe(
+                step, float(metrics.get("loss", 0.0)),
+                grad_norm=(float(metrics["grad_norm"])
+                           if "grad_norm" in metrics else None),
+                nonfinite=(float(metrics["nonfinite"])
+                           if "nonfinite" in metrics else None))
         if reason is not None:
             self._emit_sdc(step, "sentinel", reason)
             raise CorruptionDetected(step, "sentinel", reason)
@@ -306,11 +314,12 @@ class Dependability:
         blocking = (not self.config.async_save) if blocking is None else blocking
         if final:
             blocking = True
-        local = (self._local_provider.state_dict()
-                 if self._local_provider is not None else None)
-        shards = (self._local_provider.shard_state_dicts()
-                  if hasattr(self._local_provider, "shard_state_dicts")
-                  else None)
+        with span(self.obs, "ckpt.local"):
+            local = (self._local_provider.state_dict()
+                     if self._local_provider is not None else None)
+            shards = (self._local_provider.shard_state_dicts()
+                      if hasattr(self._local_provider, "shard_state_dicts")
+                      else None)
         t0 = time.perf_counter()
         # mesh_meta: set by run_elastic (or the caller) so the manifest
         # records the (dp, tp, ep) grid + expert placement the state was

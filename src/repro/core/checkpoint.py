@@ -89,6 +89,7 @@ from repro.core.io_engine import (ShardIOEngine, crc32_array, fsync_path,
                                   write_npy)
 from repro.kernels.block_hash.ops import batched_block_hashes
 from repro.kernels.block_hash.ref import block_hashes_np
+from repro.obs.metrics import span
 
 _STEP_RE = re.compile(r"^step_(\d{8})$")
 _STAGING_RE = re.compile(r"^step_(\d{8})\.tmp\.(\d+)$")
@@ -216,6 +217,9 @@ class CheckpointManager:
         os.makedirs(directory, exist_ok=True)
         self._writer: Optional[threading.Thread] = None
         self._writer_err: Optional[BaseException] = None
+        # repro.obs.Observability (Dependability.attach_obs): turns on the
+        # ckpt.drain / ckpt.snapshot / ckpt.write / ckpt.commit spans
+        self.obs = None
         self._sweep_stale_staging()
 
     # ------------------------------------------------------------------
@@ -480,18 +484,20 @@ class CheckpointManager:
         recorded in the manifest so restore can rebuild expert placement
         (``reshard_state`` reads it back via ``manifest_meta``)."""
         self.wait()  # double-buffer: drain previous async write
+        obs = self.obs
         t0 = time.perf_counter()
-        kind = "full"
-        if (self.delta and self._delta_base
-                and self._chain_len + 1 < self.full_every):
-            kind = "delta"
-        # fresh lineage id per save: a walk-back + resume can regenerate a
-        # step NUMBER with different content; delta children pin the id so
-        # restore refuses to mix generations
-        sid = uuid.uuid4().hex[:16]
-        named = _flatten_named(state)
-        (shard_plan, manifest_arrays, pending_base, dirty,
-         total) = self._snapshot(named, step, kind, sid)
+        with span(obs, "ckpt.snapshot"):
+            kind = "full"
+            if (self.delta and self._delta_base
+                    and self._chain_len + 1 < self.full_every):
+                kind = "delta"
+            # fresh lineage id per save: a walk-back + resume can
+            # regenerate a step NUMBER with different content; delta
+            # children pin the id so restore refuses to mix generations
+            sid = uuid.uuid4().hex[:16]
+            named = _flatten_named(state)
+            (shard_plan, manifest_arrays, pending_base, dirty,
+             total) = self._snapshot(named, step, kind, sid)
         snapshot_s = time.perf_counter() - t0
 
         def write():
@@ -499,49 +505,14 @@ class CheckpointManager:
             staging = self._staging(step)
             self._register_staging(staging)
             try:
-                os.makedirs(staging, exist_ok=True)
-                total_b, paths = self._engine.run_jobs(
-                    [functools.partial(self._write_shard, staging, item)
-                     for item in shard_plan])
-                manifest = {
-                    "step": step,
-                    "num_hosts": self.num_hosts,
-                    "codec": self.codec_name,
-                    "kind": kind,
-                    "arrays": manifest_arrays,
-                }
-                if mesh_meta is not None:
-                    manifest["mesh"] = dict(mesh_meta)
-                if local_shards is not None:
-                    manifest["local_shards"] = [int(sd.get("shard", k))
-                                                for k, sd in
-                                                enumerate(local_shards)]
-                mpath = os.path.join(staging, f"manifest_h{self.host_id}.json")
-                paths.append(write_json(mpath, manifest))
-                if local_state is not None:
-                    lpath = os.path.join(staging,
-                                         f"local_h{self.host_id}.json")
-                    paths.append(write_json(lpath, local_state))
-                for k, sd in enumerate(local_shards or ()):
-                    idx = int(sd.get("shard", k))
-                    spath = os.path.join(staging, f"local_s{idx:05d}.json")
-                    paths.append(write_json(spath, sd))
-                apath = os.path.join(staging, f"ack_h{self.host_id}")
-                open(apath, "w").close()
-                paths.append(apath)
-                self._engine.finalize(staging, paths)
-                # commit when all hosts acked (single-process: immediately)
-                acks = [os.path.exists(os.path.join(staging, f"ack_h{h}"))
-                        for h in range(self.num_hosts)]
-                if all(acks) and self.host_id == 0:
-                    final = self._final(step)
-                    if os.path.exists(final):
-                        shutil.rmtree(final)
-                    os.rename(staging, final)
-                    self._clear_staging(staging)
-                    if self._engine.fsync_mode != "none":
-                        fsync_path(self.directory)  # make the rename durable
-                    self._gc()
+                with span(obs, "ckpt.write"):
+                    os.makedirs(staging, exist_ok=True)
+                    total_b, paths = self._engine.run_jobs(
+                        [functools.partial(self._write_shard, staging, item)
+                         for item in shard_plan])
+                with span(obs, "ckpt.commit"):
+                    self._commit(step, staging, paths, kind, manifest_arrays,
+                                 local_state, local_shards, mesh_meta)
             except BaseException:
                 self._unregister_staging(staging)
                 raise
@@ -574,9 +545,55 @@ class CheckpointManager:
         self._writer.start()
         return stats
 
+    def _commit(self, step: int, staging: str, paths: List[str], kind: str,
+                manifest_arrays: Dict[str, Any], local_state: Optional[Dict],
+                local_shards: Optional[List[Dict]],
+                mesh_meta: Optional[Dict]) -> None:
+        """Manifest, local state, ack and durable files in ``staging``;
+        then, once every host acked, the rename into place."""
+        manifest = {
+            "step": step,
+            "num_hosts": self.num_hosts,
+            "codec": self.codec_name,
+            "kind": kind,
+            "arrays": manifest_arrays,
+        }
+        if mesh_meta is not None:
+            manifest["mesh"] = dict(mesh_meta)
+        if local_shards is not None:
+            manifest["local_shards"] = [int(sd.get("shard", k))
+                                        for k, sd in enumerate(local_shards)]
+        mpath = os.path.join(staging, f"manifest_h{self.host_id}.json")
+        paths.append(write_json(mpath, manifest))
+        if local_state is not None:
+            lpath = os.path.join(staging, f"local_h{self.host_id}.json")
+            paths.append(write_json(lpath, local_state))
+        for k, sd in enumerate(local_shards or ()):
+            idx = int(sd.get("shard", k))
+            spath = os.path.join(staging, f"local_s{idx:05d}.json")
+            paths.append(write_json(spath, sd))
+        apath = os.path.join(staging, f"ack_h{self.host_id}")
+        open(apath, "w").close()
+        paths.append(apath)
+        self._engine.finalize(staging, paths)
+        # commit when all hosts acked (single-process: immediately)
+        acks = [os.path.exists(os.path.join(staging, f"ack_h{h}"))
+                for h in range(self.num_hosts)]
+        if all(acks) and self.host_id == 0:
+            final = self._final(step)
+            if os.path.exists(final):
+                shutil.rmtree(final)
+            os.rename(staging, final)
+            self._clear_staging(staging)
+            if self._engine.fsync_mode != "none":
+                fsync_path(self.directory)  # make the rename durable
+            self._gc()
+
     def wait(self) -> None:
+        """Drain the async writer (``ckpt.drain``); re-raise its error."""
         if self._writer is not None:
-            self._writer.join()
+            with span(self.obs, "ckpt.drain"):
+                self._writer.join()
             self._writer = None
         if self._writer_err is not None:
             err, self._writer_err = self._writer_err, None

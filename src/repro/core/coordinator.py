@@ -18,6 +18,13 @@ SDC hooks inside each superstep (all no-ops unless enabled):
   - ``dep.scrub`` at the bottom: checksums the next rotating subset of
     the freshly-produced state.
   - ``dep.check_metrics`` after the superstep: the tier-3 loss sentinel.
+
+With an ``Observability`` attached (``dep.attach_obs``) every host
+statement of a superstep runs inside one span (``repro.obs.metrics.span``):
+``data.batch`` (the next batch), ``train.dispatch`` (the step call),
+``train.sync`` (the wait for its metrics), ``train.bookkeep`` (the
+boundary poll, the step record, ``on_metrics`` and the checkpoint
+decision), or the SDC guard's and the checkpoint path's own spans.
 """
 from __future__ import annotations
 
@@ -29,6 +36,7 @@ import jax
 from repro.core.api import Dependability
 from repro.core.failures import (CorruptionDetected, FaultInjector,
                                  SimulatedFailure)
+from repro.obs.metrics import span
 
 
 def run_bsp(dep: Dependability, train_step: Callable, state, data,
@@ -54,10 +62,17 @@ def run_bsp(dep: Dependability, train_step: Callable, state, data,
     (SDC tier tripped) — run_with_recovery handles both.
     """
     history: List[Dict] = []
+    obs = dep.obs
     step = int(jax.device_get(state["step"]))
     while step < num_steps:
-        pause = stop_check() if stop_check is not None else None
-        if dep.interrupted() or pause is not None:
+        with span(obs, "train.bookkeep"):
+            pause = stop_check() if stop_check is not None else None
+            stop = dep.interrupted() or pause is not None
+            if not stop and fault_injector is not None:
+                # SDC strikes the at-rest state inside the record->verify
+                # window
+                state = fault_injector.apply_sdc(step + 1, state)
+        if stop:
             if final_save:
                 dep.save(step, state, final=True)
             # flush: the final save may have queued behind a still-running
@@ -67,46 +82,44 @@ def run_bsp(dep: Dependability, train_step: Callable, state, data,
             status = "interrupted" if pause is None else f"paused:{pause}"
             return state, status, history
 
-        if fault_injector is not None:
-            # SDC strikes the at-rest state inside the record->verify window
-            state = fault_injector.apply_sdc(step + 1, state)
         dep.verify_state(state, step + 1)      # may raise CorruptionDetected
 
-        batch = data.next_batch()
+        with span(obs, "data.batch"):
+            batch = data.next_batch()
         t0 = time.perf_counter()
-        if fault_injector is not None:
-            # fail-stop / straggle strikes DURING the superstep
-            fault_injector.check(step + 1)     # may raise SimulatedFailure
-        state, metrics = train_step(state, batch)
-        metrics = jax.device_get(metrics)      # block: end of superstep
+        with span(obs, "train.dispatch"):
+            if fault_injector is not None:
+                # fail-stop / straggle strikes DURING the superstep
+                fault_injector.check(step + 1)  # may raise SimulatedFailure
+            state, metrics = train_step(state, batch)
+        with span(obs, "train.sync"):
+            metrics = jax.device_get(metrics)  # block: end of superstep
         dt = time.perf_counter() - t0
         step += 1
 
         dep.scrub(state, step)                 # record the next scrub window
-        straggler = dep.observe_step(dt, step)
-        rec = {"step": step, "seconds": dt, "straggler": straggler,
-               **{k: float(v) for k, v in metrics.items()}}
-        history.append(rec)
-        if dep.obs is not None:
-            # one bus record per superstep — the instrumented path
-            # benchmarks/bench_obs.py holds to <2% over the bare loop
-            dep.obs.emit("train", "step", **rec)
-            dep.obs.registry.histogram("train.step_ms").observe(dt * 1e3)
-        if on_metrics:
-            on_metrics(step, rec)
+        with span(obs, "train.bookkeep"):
+            straggler = dep.observe_step(dt, step)
+            rec = {"step": step, "seconds": dt, "straggler": straggler,
+                   **{k: float(v) for k, v in metrics.items()}}
+            history.append(rec)
+            if obs is not None:
+                obs.emit("train", "step", **rec)
+                obs.registry.histogram("train.step_ms").observe(dt * 1e3)
+            if on_metrics:
+                on_metrics(step, rec)
         dep.check_metrics(step, metrics)       # may raise CorruptionDetected
 
-        if dep.should_checkpoint(step):
+        with span(obs, "train.bookkeep"):
+            why = None
+            save = dep.should_checkpoint(step)
+            if not save and proactive is not None:
+                why = proactive(step)
+        if save or why is not None:
             dep.save(step, state)
-        elif proactive is not None:
-            why = proactive(step)
-            if why is not None:
-                dep.save(step, state)
-                if dep.obs is not None:
-                    dep.obs.emit("checkpoint", "proactive", step=step,
-                                 reason=why)
-                    dep.obs.registry.counter(
-                        "checkpoint.proactive").inc()
+        if why is not None and obs is not None:
+            obs.emit("checkpoint", "proactive", step=step, reason=why)
+            obs.registry.counter("checkpoint.proactive").inc()
     dep.manager.wait()
     return state, "done", history
 

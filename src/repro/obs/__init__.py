@@ -10,11 +10,13 @@ one handle that every layer shares::
 
     obs.emit("heartbeat", "failure", host=3)
     obs.registry.counter("sdc.detected", tier="abft").inc()
+    with obs.span("data.batch"):     # histogram + span log + profiler
+        batch = data.next_batch()
 
     obs.timeline().summary()         # {"mttr_s": ..., "availability": ...}
     obs.to_scenario()                # recorded log -> replayable Scenario
-    obs.dump("out/telemetry")        # events.jsonl + trace.json +
-                                     # metrics.json + metrics.prom
+    obs.dump("out/telemetry")        # events.jsonl + trace.json (events
+                                     # and spans) + metrics.json/.prom
 """
 from __future__ import annotations
 
@@ -23,7 +25,7 @@ from typing import Any, List, Optional
 
 from repro.obs.bus import DEFAULT_CAPACITY, Event, EventBus, load_jsonl
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               Span)
+                               Span, SpanLog, span)
 from repro.obs.timeline import Incident, Timeline
 from repro.obs.export import (to_chrome_trace, to_scenario,
                               write_chrome_trace)
@@ -36,7 +38,7 @@ from repro.obs.collector import Collector
 __all__ = [
     "Observability", "EventBus", "Event", "DEFAULT_CAPACITY",
     "load_jsonl", "MetricsRegistry", "Counter", "Gauge", "Histogram",
-    "Span", "Timeline", "Incident", "to_chrome_trace",
+    "Span", "SpanLog", "span", "Timeline", "Incident", "to_chrome_trace",
     "write_chrome_trace", "to_scenario", "AnomalyEngine",
     "BeatJitterDetector", "ScrubRateDetector", "StepTimeDriftDetector",
     "make_proactive_hook", "TelemetryAgent", "Collector",
@@ -56,6 +58,12 @@ class Observability:
     # -- producing -----------------------------------------------------
     def emit(self, subsystem: str, kind: str, **data: Any) -> Event:
         return self.bus.emit(subsystem, kind, **data)
+
+    def span(self, name: str) -> Span:
+        """Time a block as ``name`` (``<layer>.<what>``): its milliseconds
+        into the histogram of that name, its interval and thread into
+        ``registry.spans``, and a profiler annotation while it runs."""
+        return self.registry.span(name)
 
     # -- derived views -------------------------------------------------
     def events(self, subsystem: Optional[str] = None,
@@ -98,7 +106,8 @@ class Observability:
             paths["events"] = self.bus._jsonl_path
         self.bus.flush()
         paths["trace"] = write_chrome_trace(
-            os.path.join(out_dir, "trace.json"), evs, self.timeline())
+            os.path.join(out_dir, "trace.json"), evs, self.timeline(),
+            spans=self.registry.spans.records())
         paths["metrics_json"] = os.path.join(out_dir, "metrics.json")
         self.registry.to_json(paths["metrics_json"])
         paths["metrics_prom"] = os.path.join(out_dir, "metrics.prom")

@@ -6,7 +6,9 @@ Two consumers of a recorded bus:
   / Perfetto JSON.  Each subsystem becomes a named track of instant
   events; closed incidents from the ``Timeline`` become duration bars on
   an "incidents" track, so a compound failure reads as one shaded span
-  with the detect/drain/restore/resume marks inside it.
+  with the detect/drain/restore/resume marks inside it.  The registry's
+  span log (``repro.obs.metrics.SpanLog``) becomes duration bars on one
+  track per thread, on the same ``time.perf_counter`` clock as the events.
 
 - ``to_scenario`` — convert a recorded event log back into a replayable
   chaos ``Scenario``, closing the record-and-replay loop the ROADMAP
@@ -31,11 +33,14 @@ Two consumers of a recorded bus:
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional, Sequence
+from typing import (TYPE_CHECKING, Any, Dict, List, Optional, Sequence,
+                    Tuple)
 
-from repro.chaos.scenario import KINDS, WINDOW_KINDS, Scenario
 from repro.obs.bus import Event
 from repro.obs.timeline import Timeline
+
+if TYPE_CHECKING:
+    from repro.chaos.scenario import Scenario
 
 # ----------------------------------------------------------------------
 # Chrome trace (catapult JSON) export
@@ -45,17 +50,22 @@ _INCIDENT_TID = 0
 
 
 def to_chrome_trace(events: Sequence[Event],
-                    timeline: Optional[Timeline] = None) -> Dict[str, Any]:
+                    timeline: Optional[Timeline] = None,
+                    spans: Sequence[Tuple[str, float, float, int]] = ()
+                    ) -> Dict[str, Any]:
     """Build a ``chrome://tracing`` / Perfetto-loadable trace dict.
 
-    Timestamps are microseconds relative to the first event; one thread
-    track per subsystem; incidents (if a timeline is given, else built
-    here) render as duration ("X") bars on track 0.
+    Timestamps are microseconds relative to the first event or span; one
+    thread track per subsystem; incidents (if a timeline is given, else
+    built here) render as duration ("X") bars on track 0; each span
+    ``(name, t_start, t_end, thread_id)`` is a duration bar on the track
+    of its thread.
     """
     events = sorted(events, key=lambda e: (e.t_mono, e.seq))
     if timeline is None:
         timeline = Timeline.from_events(events)
-    t0 = events[0].t_mono if events else 0.0
+    starts = [e.t_mono for e in events[:1]] + [s[1] for s in spans]
+    t0 = min(starts) if starts else 0.0
     tids: Dict[str, int] = {}
     trace: List[Dict[str, Any]] = [
         {"name": "thread_name", "ph": "M", "pid": _PID,
@@ -73,6 +83,16 @@ def to_chrome_trace(events: Sequence[Event],
     for sub, tid in tids.items():
         trace.append({"name": "thread_name", "ph": "M", "pid": _PID,
                       "tid": tid, "args": {"name": sub}})
+    threads: Dict[int, int] = {}
+    for name, a, b, thread in spans:
+        if thread not in threads:
+            threads[thread] = len(tids) + len(threads) + 1
+            trace.append({"name": "thread_name", "ph": "M", "pid": _PID,
+                          "tid": threads[thread],
+                          "args": {"name": f"spans: thread {thread}"}})
+        trace.append({"name": name, "ph": "X", "ts": (a - t0) * 1e6,
+                      "dur": (b - a) * 1e6, "pid": _PID,
+                      "tid": threads[thread]})
     for inc in timeline.incidents:
         end = inc.t_resume if inc.closed else timeline.t_end
         if end is None:
@@ -91,9 +111,11 @@ def to_chrome_trace(events: Sequence[Event],
 
 
 def write_chrome_trace(path: str, events: Sequence[Event],
-                       timeline: Optional[Timeline] = None) -> str:
+                       timeline: Optional[Timeline] = None,
+                       spans: Sequence[Tuple[str, float, float, int]] = ()
+                       ) -> str:
     with open(path, "w") as f:
-        json.dump(to_chrome_trace(events, timeline), f, indent=2)
+        json.dump(to_chrome_trace(events, timeline, spans), f, indent=2)
         f.write("\n")
     return path
 
@@ -112,6 +134,9 @@ def to_scenario(events: Sequence[Event],
     run (a "production" log).  The result is validated — it replays
     through ``run_scenario_elastic`` or ``ControlPlaneSim`` directly.
     """
+    # deferred: repro.chaos imports repro.core, whose layers import
+    # repro.obs for their spans
+    from repro.chaos.scenario import KINDS
     events = sorted(events, key=lambda e: (e.t_mono, e.seq))
     chaos_evs = [e for e in events if e.subsystem == "chaos"]
     declarative = [e for e in chaos_evs if e.kind in KINDS]
@@ -123,6 +148,7 @@ def to_scenario(events: Sequence[Event],
 def _from_declarative(chaos_evs: Sequence[Event],
                       declarative: Sequence[Event],
                       name: Optional[str]) -> Scenario:
+    from repro.chaos.scenario import WINDOW_KINDS, Scenario
     meta: Dict[str, Any] = {}
     for e in chaos_evs:
         if e.kind == "scenario":
@@ -161,6 +187,7 @@ def _host_of(ev: Event) -> Optional[int]:
 def _from_detections(events: Sequence[Event],
                      name: Optional[str]) -> Scenario:
     """Derive a time-clock scenario from raw detection events."""
+    from repro.chaos.scenario import Scenario
     t0 = events[0].t_mono if events else 0.0
     sc = Scenario(name or "derived-replay", clock="time")
     dead: set = set()

@@ -11,15 +11,21 @@ checksum is the mod-2^32 sum of its block hashes, so scrub and delta share
 one pass over the bytes.  Host leaves reuse the zero-copy ``crc32_array`` from
 core/io_engine.py.  Either way a leaf's checksum is a plain int, stable
 across recomputation on identical bytes.
+
+``launch`` splits a batch in two, so that the scrubber can time the
+dispatch (``sdc.reduce``) apart from the wait for its result; it counts
+the bytes of the device leaves it hands to the reduction into the
+``sdc.checksummed_bytes`` counter of the ``Observability`` it is given.
 """
 from __future__ import annotations
 
-from typing import Any, List, Tuple
+from typing import Any, Callable, List, Tuple
 
 import jax
 import numpy as np
 
 from repro.kernels.block_hash.ops import checksum_words
+from repro.obs.metrics import span
 
 
 @jax.jit
@@ -42,20 +48,30 @@ def leaf_checksum(leaf: Any) -> int:
     return _host_crc(np.asarray(leaf))
 
 
-def checksums(leaves: List[Any]) -> List[int]:
-    """Checksum many leaves: ONE jitted device reduction + one device_get
+def launch(leaves: List[Any], obs=None) -> Callable[[], List[int]]:
+    """Dispatch the checksums of many leaves: ONE jitted device reduction
     for all device leaves (per-leaf dispatch would dominate the scrub cost
-    on small states), host crc32 for the rest."""
-    dev_idx = [i for i, v in enumerate(leaves) if isinstance(v, jax.Array)]
-    out: List[Any] = [None] * len(leaves)
-    if dev_idx:
-        sums = jax.device_get(_device_sums([leaves[i] for i in dev_idx]))
-        for i, s in zip(dev_idx, sums):
-            out[i] = int(s)
-    for i, v in enumerate(leaves):
-        if out[i] is None:
-            out[i] = _host_crc(np.asarray(v))
-    return out
+    on small states).  Returns a function that waits for it (one
+    device_get) and adds host crc32 for the rest, in ``leaves`` order."""
+    dev = [v for v in leaves if isinstance(v, jax.Array)]
+    sums = None
+    if dev:
+        with span(obs, "sdc.reduce"):
+            sums = _device_sums(dev)
+        if obs is not None:
+            obs.registry.counter("sdc.checksummed_bytes").inc(
+                sum(v.size * v.dtype.itemsize for v in dev))
+
+    def fetch() -> List[int]:
+        got = iter(jax.device_get(sums) if dev else ())
+        return [int(next(got)) if isinstance(v, jax.Array)
+                else _host_crc(np.asarray(v)) for v in leaves]
+    return fetch
+
+
+def checksums(leaves: List[Any]) -> List[int]:
+    """Checksum many leaves: ``launch`` them and wait."""
+    return launch(leaves)()
 
 
 def named_leaves(tree) -> List[Tuple[str, Any]]:

@@ -10,6 +10,13 @@ the leaf.  With ``fraction=f`` each call checksums ceil(f * num_leaves)
 leaves, so a full-state scrub is amortized over 1/f steps (f=1 covers
 every leaf every step; the bench quantifies the cost curve).
 
+With an ``Observability`` (``obs``, handed over by
+``Dependability.attach_obs``) each ``record`` and ``verify`` opens three
+spans: ``sdc.leaves`` (naming the leaves and choosing the subset),
+``sdc.reduce`` (dispatching the checksum program) and ``sdc.fetch``
+(waiting for its result and comparing), and counts the bytes checksummed
+(``sdc.checksummed_bytes``).
+
 The scrubber is windowed, not historical: only the most recent record is
 verifiable, because older baselines predate legitimate updates.  Coverage
 is therefore probabilistic for f < 1 — a flip in an un-scrubbed leaf rides
@@ -20,7 +27,8 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional
 
-from repro.sdc.checksum import checksums, named_leaves
+from repro.obs.metrics import span
+from repro.sdc.checksum import checksums, launch, named_leaves
 
 
 class StateScrubber:
@@ -31,8 +39,7 @@ class StateScrubber:
         self._cursor = 0
         self._window: Dict[str, int] = {}    # leaf name -> checksum
         self._window_step: Optional[int] = None
-        self.leaves_scrubbed = 0             # cumulative, for the bench
-        self.mismatches: List[str] = []      # every leaf ever flagged
+        self.obs = None                      # repro.obs.Observability
 
     # ------------------------------------------------------------------
     def _subset(self, names: List[str]) -> List[str]:
@@ -45,12 +52,13 @@ class StateScrubber:
     def record(self, state, step: int) -> List[str]:
         """Checksum the next rotation subset of ``state``; returns the
         covered leaf names.  Call right after the state is produced."""
-        leaves = dict(named_leaves(state))
-        subset = self._subset(sorted(leaves))
-        self._window = dict(zip(subset, checksums([leaves[n]
-                                                   for n in subset])))
-        self._window_step = step
-        self.leaves_scrubbed += len(subset)
+        with span(self.obs, "sdc.leaves"):
+            leaves = dict(named_leaves(state))
+            subset = self._subset(sorted(leaves))
+        fetch = launch([leaves[n] for n in subset], self.obs)
+        with span(self.obs, "sdc.fetch"):
+            self._window = dict(zip(subset, fetch()))
+            self._window_step = step
         return subset
 
     def verify(self, state) -> List[str]:
@@ -59,12 +67,13 @@ class StateScrubber:
         update consumes the state."""
         if not self._window:
             return []
-        leaves = dict(named_leaves(state))
-        names = [n for n in self._window if n in leaves]
-        got = checksums([leaves[n] for n in names])
-        bad = [n for n, g in zip(names, got) if g != self._window[n]]
-        self.mismatches.extend(bad)
-        return bad
+        with span(self.obs, "sdc.leaves"):
+            leaves = dict(named_leaves(state))
+            names = [n for n in self._window if n in leaves]
+        fetch = launch([leaves[n] for n in names], self.obs)
+        with span(self.obs, "sdc.fetch"):
+            return [n for n, g in zip(names, fetch())
+                    if g != self._window[n]]
 
     def full_checksums(self, state) -> Dict[str, int]:
         """Checksum every leaf (save-time verification / debugging)."""

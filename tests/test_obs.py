@@ -11,8 +11,9 @@ import numpy as np
 import pytest
 
 from repro.obs import (DEFAULT_CAPACITY, Event, EventBus, MetricsRegistry,
-                       Observability, Timeline, load_jsonl, to_chrome_trace,
-                       to_scenario)
+                       Observability, SpanLog, Timeline, load_jsonl, span,
+                       to_chrome_trace, to_scenario)
+from repro.obs.metrics import NO_SPAN
 
 # ---------------------------------------------------------------------------
 # event bus
@@ -238,6 +239,53 @@ def test_span_times_into_histogram():
     assert h.count == 1 and h.p50 == pytest.approx(sp.seconds * 1e3)
 
 
+def test_span_records_interval_thread_and_histogram():
+    obs = Observability()
+    t_before = time.perf_counter()
+    with obs.span("data.batch") as sp:
+        time.sleep(0.005)
+
+    def write():
+        with obs.span("ckpt.write"):
+            pass
+    worker = threading.Thread(target=write)
+    worker.start()
+    worker.join(timeout=10)
+    assert not worker.is_alive()
+    (n0, a0, b0, th0), (n1, a1, b1, th1) = obs.registry.spans.records()
+    assert (n0, n1) == ("data.batch", "ckpt.write")
+    assert t_before <= a0 < b0 <= a1 <= b1 <= time.perf_counter()
+    assert b0 - a0 == pytest.approx(sp.seconds) and sp.seconds >= 0.005
+    assert th0 == threading.get_ident() and th1 == worker.ident != th0
+    h = obs.registry.histogram("data.batch")
+    assert h.count == 1 and h.p50 == pytest.approx(sp.seconds * 1e3)
+    assert obs.registry.spans.dropped == 0
+
+
+def test_span_log_drops_the_oldest_past_its_size():
+    reg = MetricsRegistry()
+    reg.spans = SpanLog(capacity=3)
+    for i in range(5):
+        with reg.span(f"train.s{i}"):
+            pass
+    assert [r[0] for r in reg.spans.records()] == ["train.s2", "train.s3",
+                                                   "train.s4"]
+    assert reg.spans.dropped == 2
+    with pytest.raises(ValueError):
+        SpanLog(capacity=0)
+
+
+def test_span_without_observability_is_the_shared_noop():
+    a, b = span(None, "data.batch"), span(None, "train.sync")
+    assert a is b is NO_SPAN
+    with a:
+        pass
+    obs = Observability()
+    with span(obs, "train.sync"):
+        pass
+    assert [r[0] for r in obs.registry.spans.records()] == ["train.sync"]
+
+
 def test_prometheus_text_format():
     reg = MetricsRegistry()
     reg.counter("serve.tokens").inc(42)
@@ -352,6 +400,42 @@ def test_chrome_trace_has_tracks_and_incident_bars():
     assert bars[0]["name"] == "incident:heartbeat.failure"
     assert bars[0]["dur"] == pytest.approx(1.0e6)          # us
     assert trace["otherData"]["summary"]["incidents"] == 1
+
+
+def test_chrome_trace_renders_spans_per_thread():
+    events = [_ev(1.0, "train", "step", step=1)]
+    spans = [("data.batch", 1.5, 1.75, 11), ("ckpt.write", 0.5, 2.5, 22)]
+    trace = to_chrome_trace(events, spans=spans)
+    bars = {t["name"]: t for t in trace["traceEvents"]
+            if t["ph"] == "X"}
+    assert set(bars) == {"data.batch", "ckpt.write"}
+    # microseconds from the earliest event or span (the write's start)
+    assert bars["ckpt.write"]["ts"] == 0.0
+    assert bars["ckpt.write"]["dur"] == pytest.approx(2.0e6)
+    assert bars["data.batch"]["ts"] == pytest.approx(1.0e6)
+    assert bars["data.batch"]["dur"] == pytest.approx(0.25e6)
+    step = [t for t in trace["traceEvents"] if t["name"] == "train.step"]
+    assert step[0]["ts"] == pytest.approx(0.5e6)
+    tracks = {t["tid"]: t["args"]["name"] for t in trace["traceEvents"]
+              if t["ph"] == "M"}
+    span_tids = {bars["data.batch"]["tid"], bars["ckpt.write"]["tid"]}
+    assert len(span_tids) == 2
+    assert sorted(tracks[t] for t in span_tids) == ["spans: thread 11",
+                                                   "spans: thread 22"]
+    assert step[0]["tid"] not in span_tids
+
+
+def test_dump_writes_the_span_log_into_the_trace(tmp_path):
+    obs = Observability()
+    obs.emit("train", "step", step=1)
+    with obs.span("train.sync"):
+        pass
+    paths = obs.dump(str(tmp_path))
+    with open(paths["trace"]) as f:
+        trace = json.load(f)
+    assert [t["name"] for t in trace["traceEvents"]
+            if t["ph"] == "X"] == ["train.sync"]
+    obs.close()
 
 
 def test_to_scenario_declarative_round_trip_is_lossless():

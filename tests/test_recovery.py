@@ -103,3 +103,66 @@ def test_young_daly_policy_in_loop(tmp_path):
     assert dep.manager.latest_step() is not None   # bootstrap save happened
     assert dep.policy.ckpt_cost_s is not None      # C measured online
     dep.stop()
+
+
+LOOP = ("data.batch", "train.dispatch", "train.sync")
+
+
+def _union_s(intervals):
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+def test_run_bsp_spans_cover_the_superstep(tmp_path):
+    """With an Observability attached, each superstep opens data.batch,
+    train.dispatch and train.sync once, in that order, and the program's
+    spans on the training thread (loop, SDC guard, checkpoint) cover
+    nearly all of the loop's wall time."""
+    import threading
+
+    from repro.obs import Observability
+    cfg = get_config("granite-3-8b", tiny=True)
+    steps = 8
+    step_fn = jax.jit(make_train_step(cfg, total_steps=steps))
+    state = init_state(cfg, KEY)
+    data = make_pipeline(cfg, 16, 4)
+    step_fn(state, data.next_batch())              # compile outside the loop
+    dep = _dep(tmp_path, scrub=True, scrub_fraction=0.5, sentinel=True)
+    dep.register_local_state(data)
+    obs = Observability()
+    dep.attach_obs(obs)
+    _, status, hist = run_bsp(dep, step_fn, state, data, steps)
+    dep.stop()
+    assert status == "done" and len(hist) == steps
+    me = threading.get_ident()
+    mine = [r for r in obs.registry.spans.records() if r[3] == me]
+    assert [n for n, *_ in mine if n in LOOP] == list(LOOP) * steps
+    assert {n for n, *_ in mine} >= {
+        "train.bookkeep", "sdc.leaves", "sdc.reduce", "sdc.fetch",
+        "sdc.loss", "ckpt.local", "ckpt.snapshot", "ckpt.write",
+        "ckpt.commit"}
+    t0 = min(a for _, a, _, _ in mine)
+    t1 = max(b for _, _, b, _ in mine)
+    covered = _union_s([(a, b) for _, a, b, _ in mine])
+    assert covered >= 0.9 * (t1 - t0), (covered, t1 - t0)
+    assert obs.registry.spans.dropped == 0
+
+
+def test_run_bsp_records_nothing_without_observability(tmp_path,
+                                                      monkeypatch):
+    from repro.obs import metrics
+    opened = []
+    monkeypatch.setattr(metrics.Span, "__enter__",
+                        lambda self: opened.append(self.hist.name))
+    cfg = get_config("granite-3-8b", tiny=True)
+    step_fn = jax.jit(make_train_step(cfg, total_steps=4))
+    data = make_pipeline(cfg, 16, 4)
+    dep = _dep(tmp_path, scrub=True, sentinel=True)
+    dep.register_local_state(data)
+    _, status, _ = run_bsp(dep, step_fn, init_state(cfg, KEY), data, 4)
+    dep.stop()
+    assert status == "done" and opened == []

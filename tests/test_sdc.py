@@ -9,6 +9,7 @@ import glob
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -17,6 +18,7 @@ from repro.core import (CheckpointManager, CorruptionDetected, Dependability,
                         run_with_recovery)
 from repro.data import make_pipeline
 from repro.models import get_config
+from repro.obs import Observability
 from repro.sdc import LossSentinel, StateScrubber, leaf_checksum, named_leaves
 from repro.train import init_state, make_train_step
 
@@ -110,13 +112,39 @@ def test_scrubber_pinpoints_corrupted_leaf():
 
 
 def test_scrubber_rotation_covers_all_leaves():
-    state = {f"w{i}": np.full((4,), float(i), np.float32) for i in range(8)}
+    state = {f"w{i}": jnp.full((4,), float(i), jnp.float32) for i in range(8)}
     scr = StateScrubber(fraction=0.25)         # 2 of 8 leaves per record
+    scr.obs = Observability()
     seen = set()
     for s in range(4):
         seen.update(scr.record(state, s))
     assert len(seen) == 8                      # full sweep after 1/f steps
-    assert scr.leaves_scrubbed == 8
+    # every leaf checksummed once: 8 leaves of 4 float32
+    assert scr.obs.registry.counter("sdc.checksummed_bytes").value == 8 * 16
+
+
+def test_scrubber_counts_the_device_bytes_it_checksums():
+    """``sdc.checksummed_bytes`` is the bytes of the device leaves of each
+    subset handed to the checksum program, in ``record`` and ``verify``
+    alike; host leaves take the crc32 path and are not counted.  Each
+    call opens ``sdc.leaves``, ``sdc.reduce`` and ``sdc.fetch`` in turn."""
+    state = {"a": jnp.ones((3, 5), jnp.float32),
+             "b": jnp.ones((64,), jnp.bfloat16),
+             "c": jnp.arange(7, dtype=jnp.int32),
+             "h": np.ones((9,), np.float64)}
+    obs = Observability()
+    scr = StateScrubber(fraction=0.5)
+    scr.obs = obs
+    size = {n: (0 if isinstance(x, np.ndarray) else x.size * x.dtype.itemsize)
+            for n, x in named_leaves(state)}
+    want = 0
+    for s in range(3):
+        names = scr.record(state, s)
+        assert scr.verify(state) == []
+        want += 2 * sum(size[n] for n in names)
+    assert obs.registry.counter("sdc.checksummed_bytes").value == want > 0
+    order = [r[0] for r in obs.registry.spans.records()]
+    assert order == ["sdc.leaves", "sdc.reduce", "sdc.fetch"] * 6
 
 
 def test_scrubber_reset_clears_window():
