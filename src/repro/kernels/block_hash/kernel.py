@@ -1,11 +1,11 @@
 """Per-block position-weighted mod-2^32 hash kernel (pl.pallas_call).
 
 One pass over a leaf's storage words produces a hash per fixed-size block —
-the primitive behind both incremental ("delta") checkpointing (a block
-whose hash matches the last committed checkpoint never crosses the
-device->host link) and the SDC scrubber's leaf checksums (a leaf checksum
-is the mod-2^32 sum of its block hashes, so scrub and delta share one
-reduction idiom; see repro/sdc/checksum.py).
+the primitive behind incremental ("delta") checkpointing (a block whose
+hash matches the last committed checkpoint never crosses the device->host
+link).  The SDC scrubber's leaf checksum is the mod-2^32 sum of these
+block hashes, taken in a pass of its own over the leaf's own shape (see
+ops.checksum_words and repro/sdc/checksum.py).
 
 The hash is the wraparound int32 sum of each word MULTIPLIED by an odd
 per-position weight (2j+1 for word j within its block):

@@ -5,11 +5,14 @@ bitcast to a flat run of 32-bit storage words (2-byte dtypes zero-extend,
 8-byte dtypes split into two words).  ``block_hashes`` reduces those words
 per fixed-size *element* block with odd position weights (2j+1 — see
 kernel.py for why a plain sum is too weak for dirty-block detection while
-the weighted sum still catches every single-bit flip);
-``checksum_words`` is the uint32 sum of those block hashes — so a leaf's
-scrubber checksum IS the sum of its delta-block hashes, and one hashing
-pass can serve both consumers (repro/sdc/checksum.py and
-CheckpointManager's delta mode).
+the weighted sum still catches every single-bit flip).
+
+``checksum_words`` is the scrubber's whole-leaf checksum
+(repro/sdc/checksum.py): the same words and the same weights, summed over
+the whole leaf in one pass on the leaf's own shape.  Its value is the
+uint32 sum of the leaf's block hashes, so scrub and CheckpointManager's
+delta mode share the hash value, not the pass: the block view pads and
+reshapes the words into rows, which the scrub needs neither of.
 
 Backend selection mirrors core/codec.DeviceCodec: the Pallas kernel on TPU,
 a jit'd jnp twin elsewhere (interpret-mode Pallas is only for tests — far
@@ -27,20 +30,27 @@ from repro.kernels.block_hash.kernel import hash_rows
 BLOCK_ELEMS = 65536   # default delta block: 64 Ki elements (256 KiB fp32)
 
 
-def words_view(x):
-    """Flat int32 view of a leaf's storage words (same bits the host-side
-    oracle in ref.py hashes).  int32 rather than uint32 so the kernel's
-    adds stay on the natively supported type; wraparound is identical."""
-    x = x.reshape(-1)
+def _storage_words(x):
+    """int32 storage words of a leaf of a 1-, 2- or 4-byte dtype, in the
+    leaf's own shape: 4-byte words as they are, narrower ones
+    zero-extended."""
     size = x.dtype.itemsize
     if size == 4:
         return jax.lax.bitcast_convert_type(x, jnp.int32)
     if size == 2:
         return jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.int32)
-    if size == 1:
-        return jax.lax.bitcast_convert_type(x, jnp.uint8).astype(jnp.int32)
-    # 8-byte dtypes bitcast to a trailing (..., 2) int32 axis
-    return jax.lax.bitcast_convert_type(x, jnp.int32).reshape(-1)
+    return jax.lax.bitcast_convert_type(x, jnp.uint8).astype(jnp.int32)
+
+
+def words_view(x):
+    """Flat int32 view of a leaf's storage words (same bits the host-side
+    oracle in ref.py hashes).  int32 rather than uint32 so the kernel's
+    adds stay on the natively supported type; wraparound is identical."""
+    x = x.reshape(-1)
+    if x.dtype.itemsize == 8:
+        # 8-byte dtypes bitcast to a trailing (..., 2) int32 axis
+        return jax.lax.bitcast_convert_type(x, jnp.int32).reshape(-1)
+    return _storage_words(x)
 
 
 def words_per_element(dtype) -> int:
@@ -111,12 +121,33 @@ def batched_block_hashes(leaves, block_elems: int = BLOCK_ELEMS, *,
     return out
 
 
-def checksum_words(x, block_elems: int = BLOCK_ELEMS):
-    """Whole-leaf checksum = uint32 sum of the leaf's block hashes — the
-    scrubber's per-leaf checksum, traceable inside a larger jit.  Built
-    from the SAME weighted block reduction delta mode uses, so one pass
-    genuinely serves both (and a single-bit flip still changes exactly one
-    block hash by a nonzero delta, hence the total)."""
-    h = _block_hashes(x, block_elems, False, False)
-    s = jnp.sum(jax.lax.bitcast_convert_type(h, jnp.int32))
-    return jax.lax.bitcast_convert_type(s.astype(jnp.int32), jnp.uint32)
+def _block_positions(shape, block_elems):
+    """Each element's flat index mod ``block_elems`` (a power of two), in
+    ``shape``: per-axis iotas times their strides, in wrapping int32 — the
+    low bits of a sum or product depend only on the low bits of its
+    terms, so the wraparound leaves them exact."""
+    pos = jnp.zeros(shape, jnp.int32)
+    stride = 1
+    for ax in reversed(range(len(shape))):
+        if stride % block_elems:
+            pos = pos + (jax.lax.broadcasted_iota(jnp.int32, shape, ax)
+                         * (stride % block_elems))
+        stride *= shape[ax]
+    return pos & (block_elems - 1)
+
+
+def checksum_words(x):
+    """Whole-leaf checksum, traceable inside a larger jit: the uint32 sum
+    of every storage word times its block position weight (2j+1), which
+    is the uint32 sum of the leaf's block hashes.  One reduction reads
+    each word once, in the leaf's own shape: no padding, no reshape into
+    block rows (on the TPU's tiled layouts that is a copy).  A single-bit
+    flip changes exactly one block hash by a nonzero delta, hence the
+    total.  8-byte dtypes take the block path."""
+    if x.dtype.itemsize == 8:
+        h = _block_hashes(x, BLOCK_ELEMS, False, False)
+        s = jnp.sum(jax.lax.bitcast_convert_type(h, jnp.int32))
+        return jax.lax.bitcast_convert_type(s.astype(jnp.int32), jnp.uint32)
+    weights = 2 * _block_positions(x.shape, BLOCK_ELEMS) + 1
+    s = jnp.sum(_storage_words(x) * weights, dtype=jnp.int32)  # wraps
+    return jax.lax.bitcast_convert_type(s, jnp.uint32)

@@ -1,16 +1,18 @@
 """Leaf checksums for the state scrubber.
 
-Device leaves are reduced on device — bitcast to 32-bit storage words and
-reduced mod 2^32 with odd position weights (one cheap pass, no
-device->host transfer of the data; a single flipped bit changes exactly
-one word by ±2^k, hence its block hash by ±2^k*(2j+1) — an odd multiple of
-2^k that can never cancel mod 2^32 — so any single-bit upset is caught).
-The word view and the reduction live in ``repro/kernels/block_hash`` — the
-SAME kernel that detects dirty blocks for incremental checkpoints: a leaf
-checksum is the mod-2^32 sum of its block hashes, so scrub and delta share
-one pass over the bytes.  Host leaves reuse the zero-copy ``crc32_array`` from
-core/io_engine.py.  Either way a leaf's checksum is a plain int, stable
-across recomputation on identical bytes.
+Device leaves are reduced on device — their 32-bit storage words summed
+mod 2^32 with odd position weights (no device->host transfer of the data;
+a single flipped bit changes exactly one word by ±2^k, hence the sum by
+±2^k*(2j+1) — an odd multiple of 2^k that can never cancel mod 2^32 — so
+any single-bit upset is caught).  The word view and the weights come from
+``repro/kernels/block_hash`` — the hash that detects dirty blocks for
+incremental checkpoints: a leaf checksum is the mod-2^32 sum of its block
+hashes, so scrub and delta share the hash value.  The scrub takes it in one
+pass over each leaf in its own shape (``checksum_words``), and one program
+returns the checksums of all the device leaves it is given as one array.
+Host leaves reuse the zero-copy ``crc32_array`` from core/io_engine.py.
+Either way a leaf's checksum is a plain int, stable across recomputation
+on identical bytes.
 
 ``launch`` splits a batch in two, so that the scrubber can time the
 dispatch (``sdc.reduce``) apart from the wait for its result; it counts
@@ -22,6 +24,7 @@ from __future__ import annotations
 from typing import Any, Callable, List, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.block_hash.ops import checksum_words
@@ -30,7 +33,9 @@ from repro.obs.metrics import span
 
 @jax.jit
 def _device_sums(leaves):
-    return [checksum_words(x) for x in leaves]
+    """(k,) uint32: the checksums of k device leaves, one array so that
+    the host reads them in one transfer."""
+    return jnp.stack([checksum_words(x) for x in leaves])
 
 
 def _host_crc(leaf) -> int:
@@ -44,7 +49,7 @@ def _host_crc(leaf) -> int:
 def leaf_checksum(leaf: Any) -> int:
     """Checksum one pytree leaf; device arrays reduce on device."""
     if isinstance(leaf, jax.Array):
-        return int(jax.device_get(_device_sums([leaf])[0]))
+        return int(jax.device_get(_device_sums([leaf]))[0])
     return _host_crc(np.asarray(leaf))
 
 
@@ -52,7 +57,8 @@ def launch(leaves: List[Any], obs=None) -> Callable[[], List[int]]:
     """Dispatch the checksums of many leaves: ONE jitted device reduction
     for all device leaves (per-leaf dispatch would dominate the scrub cost
     on small states).  Returns a function that waits for it (one
-    device_get) and adds host crc32 for the rest, in ``leaves`` order."""
+    device_get of one array) and adds host crc32 for the rest, in
+    ``leaves`` order."""
     dev = [v for v in leaves if isinstance(v, jax.Array)]
     sums = None
     if dev:
@@ -63,8 +69,8 @@ def launch(leaves: List[Any], obs=None) -> Callable[[], List[int]]:
                 sum(v.size * v.dtype.itemsize for v in dev))
 
     def fetch() -> List[int]:
-        got = iter(jax.device_get(sums) if dev else ())
-        return [int(next(got)) if isinstance(v, jax.Array)
+        got = iter(jax.device_get(sums).tolist() if dev else ())
+        return [next(got) if isinstance(v, jax.Array)
                 else _host_crc(np.asarray(v)) for v in leaves]
     return fetch
 

@@ -4,7 +4,9 @@ Each test compiles one kernel at a published width for a TPU that is
 described, not attached, and asserts the lowering is a Mosaic
 ``tpu_custom_call``: what the chip's compiler refuses (unaligned slices,
 too much VMEM) fails here, with no chip.  Interpret-mode correctness lives
-in test_kernels.py.
+in test_kernels.py.  The scrubber's checksum program, XLA's own fusion and
+no kernel, is compiled here too: on the chip's tiled layouts a copy of a
+leaf shows only in the compiled program.
 
 The topology is described only inside the module fixture, never at import
 or collection: one process at a time may load the TPU library, and every
@@ -119,3 +121,19 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text, f"{name} did not lower to Mosaic"
+
+
+# an embedding at granite-3-8b's published vocabulary (49155 rows: its size
+# not a multiple of the 65,536-word block) and its MLP weight
+@pytest.mark.parametrize("shape", [(49155, D), (D, DFF)])
+def test_scrub_checksum_reads_the_leaf_in_place_on_v5e(one_chip, shape):
+    """The checksum program reads the leaf where it lies: no pad, no
+    relayout into block rows, and no temporary that grows with the leaf
+    (a padded or relaid copy of a 805 MB embedding would be one)."""
+    from repro.sdc.checksum import _device_sums
+
+    leaf = jax.ShapeDtypeStruct(shape, F32, sharding=one_chip)
+    compiled = _device_sums.lower([leaf]).compile()
+    text = compiled.as_text()
+    assert " pad(" not in text and " reshape(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

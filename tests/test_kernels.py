@@ -311,7 +311,7 @@ def test_block_hash_detects_permutations_and_compensating_changes():
 def test_block_hash_checksum_is_sum_of_block_hashes():
     """The scrubber's leaf checksum == uint32 sum of the delta-mode block
     hashes (at the same block size — position weights restart per block)
-    — scrub and delta genuinely share one reduction."""
+    — scrub and delta share one hash value, each in its own pass."""
     from repro.kernels.block_hash.ops import (BLOCK_ELEMS, block_hashes,
                                               checksum_words)
     from repro.kernels.block_hash.ref import checksum_np

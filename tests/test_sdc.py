@@ -100,6 +100,78 @@ def test_leaf_checksum_detects_single_bit_flip():
             assert leaf_checksum(x) != leaf_checksum(flip_bit(x, bit))
 
 
+def _oracle_case(shape, dtype):
+    rng = np.random.default_rng(sum(shape) + 1)
+    if jnp.issubdtype(dtype, jnp.integer):
+        info = jnp.iinfo(dtype)
+        a = rng.integers(info.min, info.max, shape, dtype=np.int64)
+    else:
+        a = rng.standard_normal(shape)
+    return jnp.asarray(np.asarray(a).astype(dtype))
+
+
+# leaves of more than one block whose size is not a multiple of it, a
+# minor dim that does not divide it, narrow dtypes, a 0-d leaf
+@pytest.mark.parametrize("shape,dtype", [
+    ((97, 4096), jnp.float32), ((70001,), jnp.float32),
+    ((3, 5, 7000), jnp.float32), ((300, 1000), jnp.bfloat16),
+    ((513, 129), jnp.int8), ((), jnp.int32)])
+def test_device_checksum_matches_host_oracle(shape, dtype):
+    """The one-pass device checksum equals the sum of the host oracle's
+    block hashes, and a flipped bit at the first word, the last word and
+    inside the last partial block changes it (to the oracle's value)."""
+    from repro.kernels.block_hash.ops import BLOCK_ELEMS
+    from repro.kernels.block_hash.ref import checksum_np
+    from repro.sdc.checksum import _device_sums
+
+    x = _oracle_case(shape, dtype)
+    want = checksum_np(np.asarray(x))
+    assert int(jax.device_get(_device_sums([x]))[0]) == want
+    assert leaf_checksum(x) == want
+    bits = x.dtype.itemsize * 8
+    tail = (x.size // BLOCK_ELEMS) * BLOCK_ELEMS
+    for elem in (0, x.size - 1, (tail + x.size) // 2):
+        y = flip_bit(x, elem * bits + bits - 1)
+        assert leaf_checksum(y) == checksum_np(np.asarray(y)) != want
+
+
+@pytest.mark.parametrize("k", [1, 4, 9])
+def test_checksum_program_returns_one_array_read_once(k, monkeypatch):
+    """k device leaves: ``_device_sums`` returns one (k,) uint32 array,
+    and ``launch(leaves)()`` reads it with one ``jax.device_get``, host
+    leaves (crc32) kept in their place."""
+    from repro.kernels.block_hash.ref import checksum_np
+    from repro.sdc.checksum import _device_sums, _host_crc, launch
+
+    dev = [_oracle_case((i + 1, 33), jnp.float32) for i in range(k)]
+    out = _device_sums(dev)
+    assert out.dtype == jnp.uint32 and out.shape == (k,)
+    host = np.arange(5, dtype=np.float32)
+    calls = []
+    get = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda v: calls.append(v) or get(v))
+    got = launch([host] + dev)()
+    assert len(calls) == 1
+    assert got == [_host_crc(host)] + [checksum_np(np.asarray(x))
+                                       for x in dev]
+
+
+def test_checksum_program_reads_the_leaf_in_its_own_shape():
+    """The scrub's checksum is one reduction over the leaf as it is: for an
+    embedding-like leaf (its minor dim not a divisor of the 65,536-word
+    block) the program pads nothing and reshapes nothing into block rows."""
+    import re
+
+    from repro.sdc.checksum import _device_sums
+
+    leaf = jax.ShapeDtypeStruct((3, 4096 + 8), jnp.float32)
+    text = _device_sums.lower([leaf]).as_text()
+    assert "stablehlo.pad" not in text
+    assert not re.search(r"tensor<[0-9x]*65536x", text)
+    assert "stablehlo.reshape" not in text
+
+
 def test_scrubber_pinpoints_corrupted_leaf():
     state = {"p": {"w1": jax.random.normal(KEY, (32,)),
                    "w2": jax.random.normal(jax.random.fold_in(KEY, 1), (32,))},
