@@ -29,6 +29,7 @@ from typing import Dict
 
 import numpy as np
 
+from bench import reference
 from bench.common import (Spans, as_run, mark, memory_peak_bytes,
                           program_seed)
 
@@ -53,34 +54,29 @@ class Feed:
 
 def model_config(conf: Dict):
     """The program's ModelConfig for a configuration file: the registered
-    architecture cut to the file's depth, with the file's normalisation
-    epsilon (the program takes it as an option).  Every width the file
-    states must be the one that runs."""
+    architecture with the values the reference module's ``FIELDS`` table
+    sets from the file (depth, epsilon, a tied or untied head), and every
+    value it checks (each width) equal to the file's."""
     import dataclasses
 
     from repro.models import get_config
     c = as_run(conf)
-    eps = c["rms_norm_eps" if c["family"] == "dense" else "layer_norm_epsilon"]
+    table = reference.load(conf).FIELDS[c["family"]]
+    missing = sorted(k for k, f in table.items()
+                     if f.derive is None and k not in c)
+    if missing:
+        raise ValueError(f"{c['name']}: the file lacks {missing}")
     cfg = get_config(c["model"], tiny=c.get("tiny", False))
-    cfg = dataclasses.replace(cfg, num_layers=c["num_hidden_layers"],
-                              norm_eps=eps)
-    want = {"d_model": c["hidden_size"], "vocab_size": c["vocab_size"]}
-    if c["family"] == "dense":
-        want.update(num_heads=c["num_attention_heads"],
-                    num_kv_heads=c["num_key_value_heads"],
-                    d_ff=c["intermediate_size"],
-                    resolved_head_dim=(c["hidden_size"]
-                                       // c["num_attention_heads"]),
-                    rope_theta=c["rope_theta"])
-    else:
-        want.update(d_inner=c["intermediate_size"],
-                    ssm_state=c["state_size"],
-                    conv_width=c["conv_kernel"],
-                    resolved_dt_rank=c["time_step_rank"])
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"{c['name']}: the program's sizes {got} are "
-                         f"not the file's {want}")
+    cfg = dataclasses.replace(cfg, **{f.attr: f.value(c, k)
+                                      for k, f in table.items()
+                                      if f.action == reference.SET})
+    differ = [f"{k}: the file's {f.value(c, k)!r}, the program's "
+              f"{f.attr} {getattr(cfg, f.attr)!r}"
+              for k, f in table.items() if f.action == reference.CHECK
+              and getattr(cfg, f.attr) != f.value(c, k)]
+    if differ:
+        raise ValueError(f"{c['name']}: the program does not run the "
+                         f"file's sizes: " + "; ".join(differ))
     return cfg
 
 
@@ -92,25 +88,55 @@ def scrub_period(n_leaves: int, fraction: float) -> int:
     return n_leaves // math.gcd(n_leaves, k)
 
 
-def _named_norms(cfg, vocab: int):
+def _layer_leaves(layer, prefix: str, out: Dict) -> None:
+    """Each array of one layer's subtree under ``prefix`` and its own key,
+    as the reference names its layers' weights."""
+    for k, v in layer.items():
+        if isinstance(v, dict):
+            _layer_leaves(v, prefix, out)
+        elif prefix + k in out:
+            raise ValueError(f"two leaves of one layer are named {k!r}")
+        else:
+            out[prefix + k] = v
+
+
+def named_leaves(p, vocab: int) -> Dict:
+    """A params-shaped tree of the program's, as {name: array}, named as
+    the reference names its leaves (``bench/reference/train.py``): the
+    embedding and an untied head cut to the file's ``vocab`` rows, and
+    each layer's arrays as ``L<layer>.<key>``.  A scanned stack holds
+    layer ``r * len(pattern) + p`` in row ``r`` of ``blocks.l<p>``; an
+    unscanned one holds layer ``i`` in ``layers.layer_<i>``."""
+    import jax
+    out = {"embed": p["embed"]["tok"][:vocab],
+           "final_norm": p["final_norm"]}
+    if "lm_head" in p:
+        out["head"] = p["lm_head"][:, :vocab]
+    layers = {}
+    if "blocks" in p:
+        period = len(p["blocks"])
+        for pos in range(period):
+            block = p["blocks"][f"l{pos}"]
+            for r in range(jax.tree.leaves(block)[0].shape[0]):
+                layers[r * period + pos] = jax.tree.map(
+                    lambda x, r=r: x[r], block)
+    else:
+        for name, layer in p["layers"].items():
+            layers[int(name.removeprefix("layer_"))] = layer
+    for i in sorted(layers):
+        _layer_leaves(layers[i], f"L{i}.", out)
+    return out
+
+
+def _named_norms(vocab: int):
     """jitted: (tree, base, scale) -> {leaf name: norm of (tree - base) *
-    scale} over params-shaped trees, named as the reference names them
-    (``bench/reference/train.py``).  Inside one program, so no difference
-    is held on the device."""
+    scale} over params-shaped trees (``named_leaves``).  Inside one
+    program, so no difference is held on the device."""
     import jax
     import jax.numpy as jnp
 
-    def leaves(p):
-        out = {"embed": p["embed"]["tok"][:vocab],
-               "final_norm": p["final_norm"]}
-        for k, v in p["blocks"]["l0"].items():
-            for kk, vv in (v.items() if isinstance(v, dict) else [(k, v)]):
-                for i in range(cfg.num_layers):
-                    out[f"L{i}.{kk}"] = vv[i]
-        return out
-
     def norms(tree, base, scale):
-        a, b = leaves(tree), leaves(base)
+        a, b = named_leaves(tree, vocab), named_leaves(base, vocab)
         return {k: jnp.linalg.norm((a[k] - b[k]) * scale) for k in a}
     return jax.jit(norms)
 
@@ -169,7 +195,7 @@ def run(ctx: Dict) -> Dict:
                 "readings": {"losses": []}}
     feed = Feed(data)
     tracer = ctx["tracer"]
-    norms, bit_sums = _named_norms(cfg, conf["vocab_size"]), _bit_sums()
+    norms, bit_sums = _named_norms(conf["vocab_size"]), _bit_sums()
     try:
         with mesh_context(mesh):
             step_fn = jax.jit(
@@ -300,13 +326,12 @@ def run(ctx: Dict) -> Dict:
 def check(ctx: Dict, out: Dict) -> Dict:
     """The numbers compared, each with its limit: the program's first three
     steps against the reference's, and the checkpoint round trip."""
-    from bench.reference import model as ref
     from bench.reference import train as rtrain
     conf, traffic, limits = ctx["config"], ctx["traffic"], ctx["limits"]
-    m = ref.dims(conf)
+    ref = reference.load(conf)
     rows = traffic["rows_per_data_replica"] * conf["mesh"]["data"]
-    want = rtrain.readings(m, traffic["optimizer"], program_seed(ctx["seed"]),
-                           rows, traffic["seq_len"])
+    want = rtrain.readings(ref, ref.dims(conf), traffic["optimizer"],
+                           program_seed(ctx["seed"]), rows, traffic["seq_len"])
     got = rtrain.compare(out["readings"], want)
     if "restore_mismatches" in out["readings"]:
         got["restore_mismatches"] = out["readings"]["restore_mismatches"]

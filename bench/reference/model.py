@@ -1,5 +1,6 @@
 """Plain float32 reference of the two model families the benchmark runs:
-a dense Granite-style decoder and a Mamba-1 (Falcon-Mamba) stack.
+a dense Granite-style decoder and a Mamba-1 (Falcon-Mamba) stack, with the
+output head tied to the embedding or not, as the file says.
 
 Written from the published descriptions and the configuration files under
 ``bench/configs``, in straightforward ``jax.numpy``: no kernels, no cache,
@@ -15,16 +16,20 @@ configurations state).
 Weights are drawn from the seed by the recipe the program's initialiser
 follows (the same key splits and shapes), so one seed gives both the same
 weights without either taking anything from the other.
+
+It is the reference module of every configuration file that names none
+(``bench/reference/__init__.py``): besides the model it holds the two
+families' file keys (``FIELDS``), leaf names and FLOP counts.
 """
 from __future__ import annotations
 
-import math
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
 from bench.common import as_run
+from bench.reference import CHECK, SET, Field
 
 HI = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -47,6 +52,35 @@ def mm(spec: str, a, b, prec: str):
 # configuration
 # --------------------------------------------------------------------------
 
+# What the training driver does with each key of a file, per ``family``.
+FIELDS = {
+    "dense": {
+        "num_hidden_layers": Field("num_layers", SET),
+        "rms_norm_eps": Field("norm_eps", SET),
+        "tie_word_embeddings": Field("tie_embeddings", SET),
+        "hidden_size": Field("d_model", CHECK),
+        "vocab_size": Field("vocab_size", CHECK),
+        "num_attention_heads": Field("num_heads", CHECK),
+        "num_key_value_heads": Field("num_kv_heads", CHECK),
+        "intermediate_size": Field("d_ff", CHECK),
+        "head_dim": Field("resolved_head_dim", CHECK, lambda c: (
+            c["hidden_size"] // c["num_attention_heads"])),
+        "rope_theta": Field("rope_theta", CHECK),
+    },
+    "ssm": {
+        "num_hidden_layers": Field("num_layers", SET),
+        "layer_norm_epsilon": Field("norm_eps", SET),
+        "tie_word_embeddings": Field("tie_embeddings", SET),
+        "hidden_size": Field("d_model", CHECK),
+        "vocab_size": Field("vocab_size", CHECK),
+        "intermediate_size": Field("d_inner", CHECK),
+        "state_size": Field("ssm_state", CHECK),
+        "conv_kernel": Field("conv_width", CHECK),
+        "time_step_rank": Field("resolved_dt_rank", CHECK),
+    },
+}
+
+
 def padded_vocab(v: int) -> int:
     """Rows of the embedding table the program draws (a multiple of 2048);
     only the first ``v`` are ever used."""
@@ -60,7 +94,8 @@ def dims(conf: Dict) -> Dict:
     c = as_run(conf)
     d = dict(family=c["family"], d=c["hidden_size"], L=c["num_hidden_layers"],
              V=c["vocab_size"], eps=c.get("rms_norm_eps",
-                                          c.get("layer_norm_epsilon")))
+                                          c.get("layer_norm_epsilon")),
+             tied=c["tie_word_embeddings"])
     if c["family"] == "dense":
         d.update(H=c["num_attention_heads"], K=c["num_key_value_heads"],
                  hd=c["hidden_size"] // c["num_attention_heads"],
@@ -115,7 +150,8 @@ def _ssm_layer(k, m):
 
 def init_params(m: Dict, key):
     """All weights, float32, from the seed's ``key``: the embedding (first
-    V rows of the padded draw), one entry per layer, the final norm."""
+    V rows of the padded draw), one entry per layer, the final norm, and
+    an untied head (first V columns of the padded draw)."""
     keys = jax.random.split(key, 4)
     emb = _normal(keys[0], (padded_vocab(m["V"]), m["d"]), m["d"] ** -0.5)
     layer = _dense_layer if m["family"] == "dense" else _ssm_layer
@@ -123,8 +159,12 @@ def init_params(m: Dict, key):
     # the program draws the layers' weights in one stacked call over the
     # layer keys; drawing each key on its own gives the same numbers
     layers = [layer(jax.random.split(k, 1)[0], m) for k in lkeys]
-    return {"embed": emb[: m["V"]], "layers": layers,
-            "final_norm": jnp.ones((m["d"],))}
+    params = {"embed": emb[: m["V"]], "layers": layers,
+              "final_norm": jnp.ones((m["d"],))}
+    if not m["tied"]:
+        head = _normal(keys[2], (m["d"], padded_vocab(m["V"])), m["d"] ** -0.5)
+        params["head"] = head[:, : m["V"]]
+    return params
 
 
 # --------------------------------------------------------------------------
@@ -202,7 +242,8 @@ def logits(m: Dict, params, tokens, prec: str = "f32"):
     for p in params["layers"]:
         x = block(p, x, m, prec)
     x = rms_norm(x, params["final_norm"], m["eps"])
-    out = mm("bsd,vd->bsv", x, params["embed"], prec)
+    out = (mm("bsd,vd->bsv", x, params["embed"], prec) if m["tied"]
+           else mm("bsd,dv->bsv", x, params["head"], prec))
     return out / m["logit_scale"] if m["family"] == "dense" else out
 
 
@@ -212,32 +253,50 @@ def loss(m: Dict, params, tokens, targets, prec: str = "f32"):
     return jnp.mean(jax.nn.logsumexp(z, -1) - gold)
 
 
+def leaf_norms(params) -> Dict[str, jax.Array]:
+    """The norm of each leaf, named as the training driver names the
+    program's leaves: ``embed``, ``final_norm``, ``head`` where untied, and
+    ``L<i>.<weight>``."""
+    out = {"embed": jnp.linalg.norm(params["embed"]),
+           "final_norm": jnp.linalg.norm(params["final_norm"])}
+    if "head" in params:
+        out["head"] = jnp.linalg.norm(params["head"])
+    for i, layer in enumerate(params["layers"]):
+        for k, v in layer.items():
+            out[f"L{i}.{k}"] = jnp.linalg.norm(v)
+    return out
+
+
 # --------------------------------------------------------------------------
-# the optimiser the training configuration states
+# operations the algorithms need (bench/flops.py)
 # --------------------------------------------------------------------------
 
-def lr_at(step: int, opt: Dict) -> float:
-    """Linear warm-up, then cosine decay to ``final_frac`` of the peak."""
-    peak, warm, total = opt["peak_lr"], opt["warmup_steps"], opt["total_steps"]
-    if step < warm:
-        return peak * step / max(warm, 1)
-    t = min(max((step - warm) / max(total - warm, 1), 0.0), 1.0)
-    ff = opt["final_frac"]
-    return peak * (ff + (1 - ff) * 0.5 * (1 + math.cos(math.pi * t)))
+def _matmul_params(c: Dict) -> int:
+    """Weights that each token meets in a matrix product, per layer."""
+    d = c["hidden_size"]
+    if c["family"] == "dense":
+        hd = d // c["num_attention_heads"]
+        h, k, f = (c["num_attention_heads"], c["num_key_value_heads"],
+                   c["intermediate_size"])
+        return d * h * hd + 2 * d * k * hd + h * hd * d + 3 * d * f
+    di, n, r = c["intermediate_size"], c["state_size"], c["time_step_rank"]
+    return d * 2 * di + di * (r + 2 * n) + r * di + di * d
 
 
-def clip(grads, max_norm: float):
-    n = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
-    scale = jnp.minimum(1.0, max_norm / jnp.maximum(n, 1e-12))
-    return jax.tree.map(lambda g: g * scale, grads)
+def _mixing(c: Dict, context: float) -> float:
+    """Forward operations per token per layer outside the weight products:
+    causal attention over ``context`` earlier positions (scores and
+    values), or the selective scan and the depthwise convolution."""
+    if c["family"] == "dense":
+        hd = c["hidden_size"] // c["num_attention_heads"]
+        return 4.0 * c["num_attention_heads"] * hd * context
+    di, n = c["intermediate_size"], c["state_size"]
+    # per (channel, state): exp(dt*A), decay*h + input, dt*x*B, C.h
+    return 7.0 * di * n + 2.0 * c["conv_kernel"] * di
 
 
-def adamw(grads, m1, m2, params, t: int, lr: float, opt: Dict):
-    b1, b2, eps, wd = opt["b1"], opt["b2"], opt["eps"], opt["weight_decay"]
-    m1 = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, m1, grads)
-    m2 = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, m2, grads)
-    c1, c2 = 1 - b1 ** t, 1 - b2 ** t
-    params = jax.tree.map(
-        lambda p, m, v: p - lr * ((m / c1) / (jnp.sqrt(v / c2) + eps)
-                                  + wd * p), params, m1, m2)
-    return params, m1, m2
+def layers_forward_per_token(c: Dict, context: float) -> float:
+    """Forward FLOPs of all the layers for one token that attends to
+    ``context`` positions (itself included)."""
+    return c["num_hidden_layers"] * (2.0 * _matmul_params(c)
+                                     + _mixing(c, context))

@@ -9,18 +9,21 @@ Readings of one run, program or reference, are three things:
 - ``change``: per leaf, the norm of the parameters' change over the three
   steps.
 
-Leaves are named ``embed``, ``final_norm`` and ``L<i>.<weight>``; the
-embedding counts only the configuration's ``vocab_size`` rows.
+Leaves are named by the reference module's ``leaf_norms``: ``embed``,
+``final_norm``, ``head`` where the head is untied, and ``L<i>.<weight>``;
+the embedding and the head count only the configuration's ``vocab_size``
+rows.  The optimiser is the one every training configuration states, so
+it lives here and not in a reference module.
 """
 from __future__ import annotations
 
+import math
 import statistics
+from types import ModuleType
 from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
-
-from bench.reference import model as ref
 
 
 def batch_tokens(seed: int, step: int, batch: int, seq: int, vocab: int):
@@ -32,31 +35,24 @@ def batch_tokens(seed: int, step: int, batch: int, seq: int, vocab: int):
     return toks[:, :-1], toks[:, 1:]
 
 
-def leaf_norms(params) -> Dict[str, jax.Array]:
-    out = {"embed": jnp.linalg.norm(params["embed"]),
-           "final_norm": jnp.linalg.norm(params["final_norm"])}
-    for i, layer in enumerate(params["layers"]):
-        for k, v in layer.items():
-            out[f"L{i}.{k}"] = jnp.linalg.norm(v)
-    return out
-
-
-def readings(m: Dict, opt: Dict, seed: int, batch: int, seq: int,
-             prec: str = "f32", rows_used: int = 0) -> Dict:
-    """Three AdamW steps of the reference from the seed's weights.
-    ``rows_used`` keeps only the first rows of each batch (a fault that
-    the comparison has to catch); 0 keeps all."""
+def readings(ref: ModuleType, m: Dict, opt: Dict, seed: int, batch: int,
+             seq: int, prec: str = "f32", rows_used: int = 0) -> Dict:
+    """Three AdamW steps of the reference module ``ref`` from the seed's
+    weights, at ``m = ref.dims(conf)``.  ``rows_used`` keeps only the first
+    rows of each batch (a fault that the comparison has to catch); 0 keeps
+    all."""
     with jax.default_matmul_precision("highest"):
-        return _readings(m, opt, seed, batch, seq, prec, rows_used or batch)
+        return _readings(ref, m, opt, seed, batch, seq, prec,
+                         rows_used or batch)
 
 
-def _readings(m, opt, seed, batch, seq, prec, rows_used):
+def _readings(ref, m, opt, seed, batch, seq, prec, rows_used):
     params0 = jax.jit(lambda k: ref.init_params(m, k))(
         jax.random.PRNGKey(seed))
     vg = jax.jit(jax.value_and_grad(
         lambda p, t, y: ref.loss(m, p, t, y, prec)))
-    upd = jax.jit(lambda g, m1, m2, p, t, lr: ref.adamw(
-        ref.clip(g, opt["clip_norm"]), m1, m2, p, t, lr, opt),
+    upd = jax.jit(lambda g, m1, m2, p, t, lr: adamw(
+        clip(g, opt["clip_norm"]), m1, m2, p, t, lr, opt),
         static_argnums=(4,))
     zeros = jax.tree.map(jnp.zeros_like, params0)
     p, m1, m2 = params0, zeros, zeros
@@ -68,10 +64,10 @@ def _readings(m, opt, seed, batch, seq, prec, rows_used):
         lval, g = vg(p, toks, tgts)
         losses.append(float(lval))
         if t == 1:
-            raw = jax.device_get(leaf_norms(g))
-            grad = jax.device_get(leaf_norms(ref.clip(g, opt["clip_norm"])))
-        p, m1, m2 = upd(g, m1, m2, p, t, ref.lr_at(t - 1, opt))
-    change = jax.device_get(leaf_norms(
+            raw = jax.device_get(ref.leaf_norms(g))
+            grad = jax.device_get(ref.leaf_norms(clip(g, opt["clip_norm"])))
+        p, m1, m2 = upd(g, m1, m2, p, t, lr_at(t - 1, opt))
+    change = jax.device_get(ref.leaf_norms(
         jax.tree.map(jnp.subtract, p, params0)))
     return {"losses": losses, "grad": {k: float(v) for k, v in grad.items()},
             "change": {k: float(v) for k, v in change.items()},
@@ -98,3 +94,34 @@ def compare(got: Dict, want: Dict) -> Dict[str, float]:
         "grad_leaf_rel": _worst_leaf(got["grad"], want["grad"]),
         "change_leaf_rel": _worst_leaf(got["change"], moving),
     }
+
+
+# --------------------------------------------------------------------------
+# the optimiser the training configuration states
+# --------------------------------------------------------------------------
+
+def lr_at(step: int, opt: Dict) -> float:
+    """Linear warm-up, then cosine decay to ``final_frac`` of the peak."""
+    peak, warm, total = opt["peak_lr"], opt["warmup_steps"], opt["total_steps"]
+    if step < warm:
+        return peak * step / max(warm, 1)
+    t = min(max((step - warm) / max(total - warm, 1), 0.0), 1.0)
+    ff = opt["final_frac"]
+    return peak * (ff + (1 - ff) * 0.5 * (1 + math.cos(math.pi * t)))
+
+
+def clip(grads, max_norm: float):
+    n = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
+    scale = jnp.minimum(1.0, max_norm / jnp.maximum(n, 1e-12))
+    return jax.tree.map(lambda g: g * scale, grads)
+
+
+def adamw(grads, m1, m2, params, t: int, lr: float, opt: Dict):
+    b1, b2, eps, wd = opt["b1"], opt["b2"], opt["eps"], opt["weight_decay"]
+    m1 = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, m1, grads)
+    m2 = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, m2, grads)
+    c1, c2 = 1 - b1 ** t, 1 - b2 ** t
+    params = jax.tree.map(
+        lambda p, m, v: p - lr * ((m / c1) / (jnp.sqrt(v / c2) + eps)
+                                  + wd * p), params, m1, m2)
+    return params, m1, m2

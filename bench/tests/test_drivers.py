@@ -17,7 +17,8 @@ def tiny_run(cell, seconds=1.0, trace=False, seed=2 ** 31 + 7):
 
 
 @pytest.mark.parametrize("cell", ["granite-train-guarded",
-                                  "falcon-mamba-train-guarded"])
+                                  "falcon-mamba-train-guarded",
+                                  "falcon-mamba-untied-train-guarded"])
 def test_train_cell(cell):
     res = tiny_run(cell)
     assert res["correct"], res["checks"]
@@ -26,6 +27,24 @@ def test_train_cell(cell):
     assert res["device"]["platform"] == "cpu"
     assert list(res)[-1] == "checks"
     assert res["checks"]["restore_mismatches"]["value"] == 0
+
+
+def test_untied_head_is_compared(monkeypatch):
+    """A file that unties the head gets an untied program and reference,
+    and the head is among the leaves compared, on both sides."""
+    from bench.reference import train as rtrain
+    seen = []
+    compare = rtrain.compare
+
+    def recording(got, want):
+        seen.append((set(got["grad"]), set(want["grad"]),
+                     set(got["change"])))
+        return compare(got, want)
+    monkeypatch.setattr(rtrain, "compare", recording)
+    res = tiny_run("falcon-mamba-untied-train-guarded")
+    assert res["correct"], res["checks"]
+    (got, want, change), = seen
+    assert "head" in got and got == want == change
 
 
 def test_bare_train_cell():

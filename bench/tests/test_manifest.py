@@ -122,6 +122,36 @@ def test_every_file_the_harness_finds_by_name(bench):
                                            m["name"] + ".py")), m["name"]
 
 
+REFERENCE_FUNCTIONS = ("dims", "init_params", "loss", "leaf_norms",
+                       "layers_forward_per_token")
+
+
+def test_every_reference_module_exports_what_the_harness_calls(bench):
+    """Each configuration's reference module (its ``reference`` key, else
+    ``model``) exists and holds the family's keys, weights, loss, leaf
+    names and FLOP terms; its table covers the file's family, with
+    attributes the program's ModelConfig has."""
+    import dataclasses
+
+    from bench import reference
+    from bench.tests import tiny
+    from repro.models import ModelConfig
+    attrs = {f.name for f in dataclasses.fields(ModelConfig)} | {
+        k for k, v in vars(ModelConfig).items() if isinstance(v, property)}
+    confs = [json.load(open(os.path.join(ROOT, c["file"])))
+             for c in bench["configs"]] + list(tiny.CONFIGS.values())
+    for conf in confs:
+        name = conf.get("reference", "model")
+        assert os.path.exists(os.path.join(BENCH_DIR, "reference",
+                                           name + ".py")), name
+        mod = reference.load(conf)
+        assert all(callable(getattr(mod, f)) for f in REFERENCE_FUNCTIONS)
+        table = mod.FIELDS[conf["family"]]
+        assert {f.action for f in table.values()} == {reference.SET,
+                                                      reference.CHECK}
+        assert {f.attr for f in table.values()} <= attrs, name
+
+
 def test_metrics_reach_every_cell(bench):
     """Every cell reports setup_s, another end-to-end metric and a
     per-layer metric; each per-layer metric moves an end-to-end metric

@@ -9,12 +9,17 @@ from bench.common import load_json
 from bench.run import manifest as benchmark
 
 # Cells the harness can run that no entry of BENCHMARK.json holds yet:
-# the state-space family, through the same training driver and reference.
-# Their configuration and limits exist only here, at the tiny size.  Each
-# reports the metrics of the admitted cell named beside it.
+# the state-space family, with a tied and with an untied head, through the
+# same training driver and reference.  Their configurations and limits
+# exist only here, at the tiny size.  Each reports the metrics of the
+# admitted cell named beside it.
 NOT_ADMITTED = [({"name": "falcon-mamba-train-guarded",
                   "config": "falcon-mamba-7b.L1", "traffic": "train-guarded",
-                  "chips": 1}, "granite-train-guarded")]
+                  "chips": 1}, "granite-train-guarded"),
+                ({"name": "falcon-mamba-untied-train-guarded",
+                  "config": "falcon-mamba-7b.untied",
+                  "traffic": "train-guarded", "chips": 1},
+                 "granite-train-guarded")]
 
 
 def manifest() -> dict:
@@ -36,16 +41,22 @@ CONFIGS = {
         "hidden_size": 64, "intermediate_size": 128,
         "num_attention_heads": 4, "num_key_value_heads": 2,
         "num_hidden_layers": 2, "vocab_size": 256, "rope_theta": 10000.0,
-        "rms_norm_eps": 1e-6, "attention_multiplier": 0.25,
-        "embedding_multiplier": 1.0, "logits_scaling": 1.0,
-        "residual_multiplier": 1.0},
+        "rms_norm_eps": 1e-6, "tie_word_embeddings": True,
+        "attention_multiplier": 0.25, "embedding_multiplier": 1.0,
+        "logits_scaling": 1.0, "residual_multiplier": 1.0},
     "falcon-mamba-7b.L1": {
         "name": "falcon-mamba-tiny", "model": "falcon-mamba-7b",
         "tiny": True, "family": "ssm", "mesh": {"data": 1, "model": 1},
         "hidden_size": 64, "intermediate_size": 128, "state_size": 4,
         "conv_kernel": 4, "time_step_rank": 4, "num_hidden_layers": 2,
-        "vocab_size": 256, "layer_norm_epsilon": 1e-6},
+        "vocab_size": 256, "layer_norm_epsilon": 1e-6,
+        "tie_word_embeddings": True},
 }
+# As published, Falcon-Mamba's head is untied: the driver unties the
+# program's from the file, and the reference draws and compares it.
+CONFIGS["falcon-mamba-7b.untied"] = dict(
+    CONFIGS["falcon-mamba-7b.L1"], name="falcon-mamba-untied-tiny",
+    tie_word_embeddings=False)
 
 # The cells' limits are set from readings at their own sizes on the chip;
 # these are set the same way from readings at the tiny sizes on the CPU
@@ -65,6 +76,8 @@ LIMITS = {
                                    "change_leaf_rel": 6e-3,
                                    "restore_mismatches": 0},
 }
+LIMITS["falcon-mamba-untied-train-guarded"] = LIMITS[
+    "falcon-mamba-train-guarded"]
 
 
 def traffic(name: str) -> dict:

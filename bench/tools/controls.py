@@ -24,18 +24,19 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
 
 
 def train(ctx, seed):
+    from bench import reference
     from bench.common import program_seed
-    from bench.reference import model as ref
     from bench.reference import train as rtrain
     conf, traffic = ctx["config"], ctx["traffic"]
+    ref = reference.load(conf)
     m, opt = ref.dims(conf), traffic["optimizer"]
     rows = traffic["rows_per_data_replica"] * conf["mesh"]["data"]
     s = program_seed(seed)
-    want = rtrain.readings(m, opt, s, rows, traffic["seq_len"])
+    want = rtrain.readings(ref, m, opt, s, rows, traffic["seq_len"])
     out = {}
     for label, kw in (("control_fp8", {"prec": "fp8"}),
                       ("fault_half_batch", {"rows_used": rows // 2})):
-        got = rtrain.readings(m, opt, s, rows, traffic["seq_len"], **kw)
+        got = rtrain.readings(ref, m, opt, s, rows, traffic["seq_len"], **kw)
         out[label] = rtrain.compare(got, want)
     return out
 
